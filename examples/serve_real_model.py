@@ -7,14 +7,14 @@ from repro.launch.serve import run
 
 
 def main():
-    out = run("qwen2-7b", batch=4, prompt_len=32, output_len=24,
-              calibrate=False)
+    out = run("qwen2-7b", smoke=True, n_requests=4, prompt_min=32,
+              prompt_max=32, output_len=24)
     m, p = out["measured"], out["predicted"]
-    print("real MiniEngine (JAX, CPU):")
+    print("real MiniEngine (JAX):")
     print(f"  throughput {m['throughput_tok_s']:8.1f} tok/s   "
           f"ttft {m['ttft_mean_s']*1e3:7.1f} ms   "
           f"tpot {m['tpot_mean_s']*1e3:6.1f} ms")
-    print("Frontier simulation (CPU-calibrated hardware profile):")
+    print(f"Frontier simulation ({out['hardware']} hardware profile):")
     print(f"  throughput {p['throughput_tok_s']:8.1f} tok/s   "
           f"ttft {p['ttft_p50_s']*1e3:7.1f} ms   "
           f"tpot {p['tpot_p50_s']*1e3:6.1f} ms")

@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from repro.api.run import Report, run
 from repro.api.spec import SimSpec, SpecError
 from repro.api.sweep import pareto, sweep
+from repro.launch.compile_cache import enable_compile_cache
 
 SUMMARY_KEYS = (
     "n_completed", "duration_s", "throughput_tok_s",
@@ -378,6 +379,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p.set_defaults(fn=_cmd_list)
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         return args.fn(args)
     except SpecError as e:

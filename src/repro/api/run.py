@@ -90,7 +90,8 @@ def build(spec: SimSpec, *,
             "through run() (repro.fleet.run_fleet), which builds each "
             "instance from a fleet-stripped sub-spec")
     spec.validate()
-    cfg = get_config(spec.model.name, smoke=spec.model.smoke)
+    cfg = get_config(spec.model.name, smoke=spec.model.smoke,
+                     layers=spec.model.layers)
     topo = spec.topology
     hw = hardware if hardware is not None \
         else _resolve_hw(topo.hardware, "topology.hardware")

@@ -27,7 +27,7 @@ import json
 from dataclasses import asdict, dataclass, field, fields
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Union
 
-from repro.configs import REGISTRY
+from repro.configs import REGISTRY, get_config
 from repro.core.hardware import HARDWARE, HardwareSpec, LinkSpec, \
     ParallelismConfig
 from repro.core.opmodels import OPMODELS
@@ -93,11 +93,17 @@ class ModelRef:
     """A model architecture by registry name (see ``repro.configs``)."""
     name: str = "qwen2-7b"
     smoke: bool = False      # reduced same-family variant (CI-sized)
+    layers: Optional[int] = None   # depth cut (widths unchanged)
 
     def validate(self) -> None:
         if self.name not in REGISTRY:
             raise SpecError(f"model.name: unknown model {self.name!r}; "
                             f"available: {sorted(REGISTRY)}")
+        if self.layers is not None:
+            try:
+                get_config(self.name, smoke=self.smoke, layers=self.layers)
+            except ValueError as e:
+                raise SpecError(f"model.layers: {e}") from None
 
 
 # ------------------------------------------------------------ topology ----
@@ -1126,6 +1132,8 @@ class SimSpec:
         # predate the field, so spec hashes and goldens stay bit-identical
         if d.get("opmodel", {}).get("calibration") is None:
             d["opmodel"].pop("calibration", None)
+        if d["model"].get("layers") is None:
+            d["model"].pop("layers", None)
         # same rule for the fabric/cost fields: unset must serialize like
         # specs that predate them
         topo = d.get("topology", {})

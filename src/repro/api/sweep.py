@@ -97,13 +97,20 @@ def sweep(base: SimSpec, axes: Mapping[str, Sequence[Any]], *,
           progress=None) -> List[Report]:
     """Run the expanded grid; return Reports in deterministic point order.
 
-    ``jobs > 1`` fans points out over a process pool.  ``jsonl`` streams
-    each finished Report as one JSON line (append; written as points
-    complete, so partial sweeps leave usable artifacts).  ``progress`` is
-    an optional ``fn(done, total, report)`` callback.
+    ``jobs > 1`` fans points out over a process pool; it is refused for
+    ``opmodel.backend: jit``, whose pricing runs on the device.  ``jsonl``
+    streams each finished Report as one JSON line (append; written as
+    points complete, so partial sweeps leave usable artifacts).
+    ``progress`` is an optional ``fn(done, total, report)`` callback.
     """
     points = expand(base, axes, mode=mode, seeds=seeds)
     total = len(points)
+    if jobs > 1 and any(spec.opmodel.backend == "jit" for spec, _ in points):
+        # a worker process that prices on the device would contend for the
+        # chip, which belongs to one process at a time
+        raise SpecError("opmodel.backend 'jit' prices steps on the device, "
+                        "which one process holds; run this sweep with "
+                        "jobs=1")
     results: List[Optional[Report]] = [None] * total
     if jobs <= 1 or total <= 1:
         for i, (spec, point) in enumerate(points):

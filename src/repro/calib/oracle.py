@@ -5,8 +5,8 @@ for this exact heterogeneous batch?" in seconds.  Three backends, one per
 rung of the fidelity ladder:
 
 ``pallas``     wall-clock timing of the real Pallas kernels in
-               ``kernels/ops.py`` (interpret mode on CPU — functional but
-               slow, so shape limits shrink; real kernels on TPU/GPU).
+               ``kernels/ops.py`` (compiled on TPU; interpret mode on
+               CPU — functional but slow, so shape limits shrink).
 ``kernelsim``  the ``VirtualKernels`` tile-level simulator: deterministic,
                fast, models wave quantization and head/tile parallelism.
 ``hlo``        the HLO-cost proxy: jit-lower the jnp reference ops,
@@ -14,9 +14,9 @@ rung of the fidelity ladder:
                price flops/bytes on the target hardware roofline.
 
 ``resolve_oracle`` picks automatically by environment ("auto"): the real
-kernels when an accelerator backend is present, the virtual kernels
-otherwise — so `python -m repro calibrate` does the right thing on both a
-laptop and a TPU VM.
+kernels when JAX's backend is a TPU, the virtual kernels otherwise — so
+`python -m repro calibrate` does the right thing on both a laptop and a
+TPU VM.
 """
 from __future__ import annotations
 
@@ -114,7 +114,7 @@ class PallasOracle(Oracle):
         self._cache: Dict[tuple, float] = {}
         import jax  # hard dep of the kernels; fail loud at construction
         self._jax = jax
-        self._on_accel = jax.default_backend() in ("tpu", "gpu")
+        self._on_accel = jax.default_backend() == "tpu"
 
     def limits(self) -> Dict[str, int]:
         if self._on_accel:
@@ -292,14 +292,9 @@ ORACLES: Dict[str, type] = {
 
 
 def default_oracle_name() -> str:
-    """Real kernels on an accelerator, the virtual-kernel sim elsewhere."""
-    try:
-        import jax
-        if jax.default_backend() in ("tpu", "gpu"):
-            return "pallas"
-    except Exception:
-        pass
-    return "kernelsim"
+    """Real kernels on a TPU, the virtual-kernel sim elsewhere."""
+    import jax
+    return "pallas" if jax.default_backend() == "tpu" else "kernelsim"
 
 
 def resolve_oracle(spec, hw: HardwareSpec) -> Oracle:

@@ -5,6 +5,9 @@
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import Optional
+
 from repro.configs.base import (
     ModelConfig, MoEConfig, ShapeConfig, SHAPES, SMOKE_SHAPE,
     TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K,
@@ -34,9 +37,18 @@ REGISTRY = {c.name: c for c in ASSIGNED + (qwen2_7b.CONFIG,)}
 ARCH_IDS = [c.name for c in ASSIGNED]
 
 
-def get_config(name: str, smoke: bool = False) -> ModelConfig:
+def get_config(name: str, smoke: bool = False,
+               layers: Optional[int] = None) -> ModelConfig:
+    """The registered config, optionally reduced (``smoke``) and with its
+    depth cut to ``layers`` (widths unchanged)."""
     cfg = REGISTRY[name]
-    return reduced(cfg) if smoke else cfg
+    cfg = reduced(cfg) if smoke else cfg
+    if layers is None:
+        return cfg
+    if not 1 <= layers <= cfg.num_layers:
+        raise ValueError(f"{cfg.name}: layers must be in [1, "
+                         f"{cfg.num_layers}], got {layers}")
+    return dataclasses.replace(cfg, num_layers=layers)
 
 
 __all__ = [

@@ -67,6 +67,10 @@ TPU_V5E = HardwareSpec(
 
 HARDWARE = {h.name: h for h in (A800_SXM4_80G, H100_SXM, TPU_V5E)}
 
+# JAX's ``device_kind`` of an attached chip -> the profile it is priced
+# with.  A chip missing here is an error, never a default.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
 
 @dataclass(frozen=True)
 class LinkSpec:

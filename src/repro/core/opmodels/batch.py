@@ -175,17 +175,15 @@ class _Terms:
             F = [t[1] for t in self._seq if t[0] == "roof"]
             if F:
                 fn = _fused_kernel(hw.peak_flops, hw.hbm_bw)
-                if fn is not None:
-                    Bt = np.stack([t[2] for t in self._seq
-                                   if t[0] == "roof"])
-                    mult = np.asarray([t[3] for t in self._seq
-                                       if t[0] == "roof"], float)
-                    out = np.asarray(fn(np.stack(F), Bt, mult), float)
-                    out = out + mult.sum() * hw.op_overhead
-                    for t in self._seq:
-                        if t[0] == "lin":
-                            out = out + t[1]
-                    return out
+                Bt = np.stack([t[2] for t in self._seq if t[0] == "roof"])
+                mult = np.asarray([t[3] for t in self._seq
+                                   if t[0] == "roof"], float)
+                out = np.asarray(fn(np.stack(F), Bt, mult), float)
+                out = out + mult.sum() * hw.op_overhead
+                for t in self._seq:
+                    if t[0] == "lin":
+                        out = out + t[1]
+                return out
         total = np.zeros(self._b)
         for t in self._seq:
             if t[0] == "roof":
@@ -202,17 +200,13 @@ _KERNELS = {}
 
 
 def _fused_kernel(peak: float, hbm: float):
-    """One jit-compiled fused roofline evaluation per hardware point.
-    Returns None when jax is unavailable (callers fall back to numpy)."""
+    """One jit-compiled fused roofline evaluation per hardware point; it
+    runs on JAX's default device."""
     key = (peak, hbm)
     if key in _KERNELS:
         return _KERNELS[key]
-    try:
-        import jax
-        import jax.numpy as jnp
-    except ImportError:                   # gated dep: numpy fallback
-        _KERNELS[key] = None
-        return None
+    import jax
+    import jax.numpy as jnp
 
     @jax.jit
     def fused(F, Bt, mult):

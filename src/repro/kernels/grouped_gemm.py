@@ -19,14 +19,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import dot_precision
+
 
 def _gg_kernel(gs_ref, x_ref, w_ref, y_ref, acc_ref, *,
-               bm: int, bn: int, bkk: int, nk: int):
+               bm: int, bn: int, bkk: int, nk: int, precision):
     e = pl.program_id(0)
     im = pl.program_id(1)
     ik = pl.program_id(3)
 
-    rows = gs_ref[0]
+    rows = gs_ref[e]
     live = (im * bm) < rows
 
     @pl.when(ik == 0)
@@ -39,7 +41,7 @@ def _gg_kernel(gs_ref, x_ref, w_ref, y_ref, acc_ref, *,
         w = w_ref[...]
         acc_ref[...] += jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -51,7 +53,7 @@ def _gg_kernel(gs_ref, x_ref, w_ref, y_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bkk", "interpret"))
 def grouped_gemm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
                  bm: int = 128, bn: int = 128, bkk: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """x (E,C,din) @ w (E,din,dout) with per-expert row validity."""
     E, C, din = x.shape
     dout = w.shape[2]
@@ -63,22 +65,30 @@ def grouped_gemm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
     Kp = math.ceil(din / bkk) * bkk
     xr = jnp.pad(x, ((0, 0), (0, Cp - C), (0, Kp - din)))
     wr = jnp.pad(w, ((0, 0), (0, Kp - din), (0, Np - dout)))
-    gs = group_sizes.astype(jnp.int32).reshape(E, 1)
+    gs = group_sizes.astype(jnp.int32)   # scalar prefetch (SMEM)
     nk = Kp // bkk
 
-    kernel = functools.partial(_gg_kernel, bm=bm, bn=bn, bkk=bkk, nk=nk)
+    kernel = functools.partial(_gg_kernel, bm=bm, bn=bn, bkk=bkk, nk=nk,
+                               precision=dot_precision(x.dtype))
     y = pl.pallas_call(
         kernel,
-        grid=(E, Cp // bm, Np // bn, nk),
-        in_specs=[
-            pl.BlockSpec((None, 1), lambda e, im, jn, ik: (e, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, bm, bkk), lambda e, im, jn, ik: (e, im, ik)),
-            pl.BlockSpec((None, bkk, bn), lambda e, im, jn, ik: (e, ik, jn)),
-        ],
-        out_specs=pl.BlockSpec((None, bm, bn), lambda e, im, jn, ik: (e, im, jn)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, Cp // bm, Np // bn, nk),
+            in_specs=[
+                pl.BlockSpec((None, bm, bkk),
+                             lambda e, im, jn, ik, gs: (e, im, ik)),
+                pl.BlockSpec((None, bkk, bn),
+                             lambda e, im, jn, ik, gs: (e, ik, jn)),
+            ],
+            out_specs=pl.BlockSpec((None, bm, bn),
+                                   lambda e, im, jn, ik, gs: (e, im, jn)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((E, Cp, Np), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(gs, xr, wr)
     return y[:, :C, :dout]

@@ -1,9 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True`` — the
-kernel body runs in Python per grid step, which validates correctness
-against ref.py.  On TPU the same ``pl.pallas_call`` compiles natively
-(``interpret=False`` is selected automatically).
+On TPU ``flash_attention``, ``decode_attention`` and ``grouped_gemm``
+compile natively through Mosaic (``tests/test_tpu_compile.py`` compiles
+them for a described v5e at qwen2-7b / mixtral-8x7b widths).  On CPU the
+same ``pl.pallas_call`` runs with ``interpret=True`` — the kernel body runs
+in Python per grid step, which validates correctness against ref.py.  Any
+other backend is an error: nothing interprets on an accelerator.
+``wkv_chunked`` does not lower for TPU (``cumsum`` has no Mosaic rule) and
+runs interpreted on CPU only.
 
 Head dims that are not MXU-lane aligned (kimi's 112) are zero-padded to the
 next multiple of 128 here, not inside the kernels.
@@ -19,7 +23,14 @@ from repro.kernels import grouped_gemm as _gg
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and interpret on CPU; backend "
+        f"{backend!r} is neither")
 
 
 def _pad_hd(x: jax.Array, align: int = 128):

@@ -1,4 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+Contractions run at ``Precision.HIGHEST``: on a TPU the default would
+round float32 operands to bf16.
+"""
 from __future__ import annotations
 
 from typing import Optional
@@ -7,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -2.0e38
+HI = jax.lax.Precision.HIGHEST
 
 
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -19,7 +24,7 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale = scale if scale is not None else hd ** -0.5
     qg = q.reshape(B, S, K, g, hd)
     s = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+                   k.astype(jnp.float32), precision=HI) * scale
     qi = jnp.arange(S)[:, None]
     ki = jnp.arange(T)[None, :]
     ok = jnp.ones((S, T), bool)
@@ -29,7 +34,8 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ok &= (qi - ki) < window
     s = jnp.where(ok[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32),
+                   precision=HI)
     return o.reshape(B, S, H, hd).astype(q.dtype)
 
 
@@ -43,11 +49,12 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = scale if scale is not None else hd ** -0.5
     qg = q.reshape(B, K, g, hd)
     s = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+                   k.astype(jnp.float32), precision=HI) * scale
     ok = jnp.arange(T)[None, :] < lengths[:, None]
     s = jnp.where(ok[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32),
+                   precision=HI)
     return o.reshape(B, H, hd).astype(q.dtype)
 
 
@@ -67,7 +74,8 @@ def wkv_ref(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     def step(S, inp):
         rt, kt, vt, wt = inp
         kv = kt[..., :, None] * vt[..., None, :]
-        y = jnp.einsum("bhi,bhij->bhj", rt, S + uf[None, :, :, None] * kv)
+        y = jnp.einsum("bhi,bhij->bhj", rt, S + uf[None, :, :, None] * kv,
+                       precision=HI)
         S = S * wt[..., :, None] + kv
         return S, y
 
@@ -81,6 +89,6 @@ def grouped_gemm_ref(x: jax.Array, w: jax.Array,
     """x (E,C,din); w (E,din,dout); rows >= group_sizes[e] are masked to 0."""
     E, C, _ = x.shape
     y = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
-                   w.astype(jnp.float32))
+                   w.astype(jnp.float32), precision=HI)
     mask = jnp.arange(C)[None, :] < group_sizes[:, None]
     return (y * mask[..., None]).astype(x.dtype)

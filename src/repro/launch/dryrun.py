@@ -1,7 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Do not move them.
+# The lines above MUST run before any other import (jax locks the platform
+# and device count at first init).  The dry run lowers for 512 host CPU
+# devices and never takes an attached chip.  Do not move them.
 
 import argparse      # noqa: E402
 import json          # noqa: E402

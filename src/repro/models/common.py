@@ -8,6 +8,7 @@ pytree (``spec_tree``) so the two can never drift structurally.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
@@ -44,24 +45,22 @@ def is_pd(x: Any) -> bool:
     return isinstance(x, PD)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def _init_one(key: jax.Array, pd: PD, dtype) -> jax.Array:
+    # jitted so the float32 draw fuses into the cast: a bf16 leaf never
+    # holds a float32 temporary of its full size on the device
     dtype = pd.dtype or dtype
     if pd.init == "zeros":
         return jnp.zeros(pd.shape, dtype)
     if pd.init == "ones":
         return jnp.ones(pd.shape, dtype)
     if pd.init == "fan_in":
-        fan_in = pd.shape[0] if len(pd.shape) == 1 else 1
+        # fan-in: product of all but the last dim, stacked layers excluded
+        fan_in = 1
         for d, a in zip(pd.shape[:-1], pd.axes[:-1]):
             if a != "layers":
-                fan_in = fan_in * d if len(pd.shape) > 1 else fan_in
-        # use product of all but last non-layer dims as fan-in
-        dims = [d for d, a in zip(pd.shape[:-1], pd.axes[:-1]) if a != "layers"]
-        fan_in = 1
-        for d in dims:
-            fan_in *= d
-        fan_in = max(fan_in, 1)
-        std = fan_in ** -0.5
+                fan_in *= d
+        std = max(fan_in, 1) ** -0.5
     else:
         std = float(pd.init)
     return (jax.random.normal(key, pd.shape, jnp.float32) * std).astype(dtype)

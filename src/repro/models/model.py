@@ -6,7 +6,7 @@ Public API (used by launch/, serving/, training/, tests/):
     pds    = model.pds()                  # param descriptors
     params = common.init_tree(key, pds, dtype)
     loss   = model.loss(params, batch)
-    logits, cache = model.prefill(params, batch)
+    logits, cache = model.prefill(params, batch)   # last=(B,) picks rows
     logits, cache = model.decode(params, cache, tokens, pos)
 """
 from __future__ import annotations
@@ -27,10 +27,16 @@ from repro.models.common import (
 )
 from repro.models.transformer import AUX_KEYS
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+
+def _logit_rows(x: jax.Array, all_logits: bool,
+                last: Optional[jax.Array]) -> jax.Array:
+    """The prefill positions whose logits are returned: every position, the
+    final one, or per row the position ``last`` (a padded prompt's end)."""
+    if all_logits:
+        return x
+    if last is None:
+        return x[:, -1:, :]
+    return jnp.take_along_axis(x, last[:, None, None], axis=1)
 
 
 def _tree_sum(trees):
@@ -90,7 +96,7 @@ class LM:
                 g = jnp.where(ok[..., None], g, 0)
                 return jax.lax.psum(g, "model")
 
-            x = shard_map(
+            x = jax.shard_map(
                 body, mesh=ax.mesh,
                 in_specs=(P("model", None), P(bspec, None)),
                 out_specs=P(bspec, None, None), check_vma=False,
@@ -214,11 +220,11 @@ class LM:
         return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
 
     def prefill(self, params, batch, *, cache_len: Optional[int] = None,
-                all_logits: bool = False):
+                all_logits: bool = False, last: Optional[jax.Array] = None):
         x = self._inputs_to_x(params, batch)
         S_total = x.shape[1]
         x, cache = self._scan_prefill(params, x, cache_len=cache_len or S_total)
-        logits = self._logits(params, x if all_logits else x[:, -1:, :])
+        logits = self._logits(params, _logit_rows(x, all_logits, last))
         return logits, cache
 
     def decode(self, params, cache, tokens, pos):
@@ -308,7 +314,7 @@ class EncDec:
         return loss, metrics
 
     def prefill(self, params, batch, *, cache_len: Optional[int] = None,
-                all_logits: bool = False):
+                all_logits: bool = False, last: Optional[jax.Array] = None):
         memory = self.encode(params, batch["frames"])
         x = self.decoder._embed(params["dec"], batch["tokens"])
         S = x.shape[1]
@@ -316,7 +322,7 @@ class EncDec:
                                               cache_len=cache_len or S,
                                               memory=memory)
         logits = self.decoder._logits(params["dec"],
-                                      x if all_logits else x[:, -1:, :])
+                                      _logit_rows(x, all_logits, last))
         return logits, cache
 
     def decode(self, params, cache, tokens, pos):

@@ -35,11 +35,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.common import PD, AxisRules, activation
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 
 def moe_pds(cfg: ModelConfig) -> Dict[str, PD]:
     moe = cfg.moe
@@ -278,7 +273,7 @@ def moe_apply(cfg: ModelConfig, p, x: jax.Array, ax: AxisRules, *,
             xspec_a = P(bspec, "model", None)
             body_a = _ft.partial(_a2a_body, cfg, E=E, E_l=E_l, tp=tp,
                                  C_r=C_r, C_e=C_e, mesh=mesh)
-            y, kept = shard_map(
+            y, kept = jax.shard_map(
                 body_a, mesh=mesh,
                 in_specs=(xspec_a, xspec_a, xspec_a, wspec_in, gspec,
                           wspec_out),
@@ -286,7 +281,7 @@ def moe_apply(cfg: ModelConfig, p, x: jax.Array, ax: AxisRules, *,
                 check_vma=False,
             )(x, ids, gates, p["w_in"], w_gate, p["w_out"])
         else:
-            y, kept = shard_map(
+            y, kept = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(xspec, xspec, xspec, wspec_in, gspec, wspec_out),
                 out_specs=(xspec, P()),

@@ -1,8 +1,10 @@
 """MiniEngine: a real (executing) continuous-batching serving engine in JAX.
 
 This is the measured system for the paper's Table-2 protocol: the simulator
-predicts its throughput; bench_e2e_accuracy compares.  CPU-runnable at
-smoke scale; the same engine drives examples/serve_real_model.py.
+predicts its throughput; bench_e2e_accuracy compares.  It runs in bf16 at
+qwen2-7b's published widths on one TPU v5e (depth cut to fit; see
+``chip_smoke.py``) and at smoke widths on the CPU; ``launch/serve.py``
+drives it.
 
 Design (vLLM-like, slot-based):
 - a fixed pool of `max_slots` sequence slots with a shared stacked KV cache
@@ -15,6 +17,7 @@ Design (vLLM-like, slot-based):
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -39,6 +42,34 @@ class ServeRequest:
     tokens: List[int] = field(default_factory=list)
 
 
+# The engine's device steps.  Each returns greedy token ids, never logits:
+# only (B,) ints cross to the host.
+def prefill_step(model, max_seq: int, params, tokens, last):
+    """One request's prefill: its first token (from position ``last``) and
+    its KV cache sized ``max_seq``."""
+    logits, cache = model.prefill(params, {"tokens": tokens},
+                                  cache_len=max_seq, last=last)
+    return jnp.argmax(logits[:, 0], -1), cache
+
+
+def decode_step(model, params, cache, tokens, pos):
+    """One decode step over every slot."""
+    logits, cache = model.decode(params, cache, tokens, pos)
+    return jnp.argmax(logits[:, 0], -1), cache
+
+
+def _insert_slot(cache, one, slot):
+    """Write a one-request prefill cache into row ``slot`` of the slot
+    cache (batch is axis 1 of the scanned group leaves, 0 of the tail)."""
+    def put(axis):
+        return lambda c_all, c_one: jax.lax.dynamic_update_slice_in_dim(
+            c_all, c_one.astype(c_all.dtype), slot, axis=axis)
+    return {"groups": jax.tree_util.tree_map(put(1), cache["groups"],
+                                             one["groups"]),
+            "tail": jax.tree_util.tree_map(put(0), cache["tail"],
+                                           one["tail"])}
+
+
 def _bucket(n: int) -> int:
     b = 16
     while b < n:
@@ -49,7 +80,7 @@ def _bucket(n: int) -> int:
 class MiniEngine:
     def __init__(self, cfg: ModelConfig, *, max_slots: int = 8,
                  max_seq: int = 256, seed: int = 0,
-                 params=None, dtype=jnp.float32):
+                 params=None, dtype=jnp.bfloat16):
         self.cfg = cfg
         self.ax = AxisRules(None)
         self.model = build_model(cfg, self.ax)
@@ -67,17 +98,23 @@ class MiniEngine:
         self.slot_tok = np.zeros(max_slots, np.int32)   # last emitted token
         self.waiting: List[ServeRequest] = []
         self.step_log: List[Dict] = []
+        self._next_rid = 0
 
-        self._prefill_jit: Dict[int, object] = {}
-        self._decode_jit = jax.jit(self.model.decode)
-        self._insert_jit = None
+        # the slot cache is donated, so it is updated in place, not copied
+        self._prefill_jit = jax.jit(
+            functools.partial(prefill_step, self.model, max_seq))
+        self._decode_jit = jax.jit(functools.partial(decode_step, self.model),
+                                   donate_argnums=1)
+        self._insert_jit = jax.jit(_insert_slot, donate_argnums=0)
 
     # ------------------------------------------------------------- intake --
     def submit(self, prompts: List[np.ndarray], max_new_tokens: int) -> List[ServeRequest]:
         now = time.perf_counter()
-        reqs = [ServeRequest(rid=i, prompt=np.asarray(p, np.int32),
+        reqs = [ServeRequest(rid=self._next_rid + i,
+                             prompt=np.asarray(p, np.int32),
                              max_new_tokens=max_new_tokens, submitted=now)
                 for i, p in enumerate(prompts)]
+        self._next_rid += len(reqs)
         self.waiting.extend(reqs)
         return reqs
 
@@ -87,37 +124,17 @@ class MiniEngine:
         bucket = min(_bucket(S), self.max_seq)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :S] = req.prompt
-        if bucket not in self._prefill_jit:
-            self._prefill_jit[bucket] = jax.jit(
-                lambda p, b: self.model.prefill(p, b, cache_len=self.max_seq,
-                                                all_logits=True))
+        # the first token comes from the TRUE last prompt position S-1
+        # (causal masking makes it independent of the padding); pad KV
+        # beyond S is never visible: decode masks t <= pos and each step
+        # overwrites slot pos before it becomes attendable.
         t0 = time.perf_counter()
-        logits, cache1 = self._prefill_jit[bucket](self.params,
-                                                   {"tokens": jnp.asarray(toks)})
-        logits.block_until_ready()
+        first, cache1 = self._prefill_jit(self.params, jnp.asarray(toks),
+                                          jnp.asarray([S - 1], jnp.int32))
+        first = int(first[0])
         dt = time.perf_counter() - t0
         self.step_log.append({"kind": "prefill", "tokens": int(S), "dur": dt})
-
-        # scatter request cache into the slot cache (per-leaf batch axis)
-        def ins_group(c_all, c_one):
-            return jax.lax.dynamic_update_slice_in_dim(
-                c_all, c_one.astype(c_all.dtype), slot, axis=1)
-
-        def ins_tail(c_all, c_one):
-            return jax.lax.dynamic_update_slice_in_dim(
-                c_all, c_one.astype(c_all.dtype), slot, axis=0)
-
-        self.cache = {
-            "groups": jax.tree_util.tree_map(ins_group, self.cache["groups"],
-                                             cache1["groups"]),
-            "tail": jax.tree_util.tree_map(ins_tail, self.cache["tail"],
-                                           cache1["tail"]),
-        }
-        # pad KV beyond S is never visible: decode masks t <= pos and each
-        # step overwrites slot pos before it becomes attendable.  The first
-        # token comes from the TRUE last prompt position S-1 (causal masking
-        # makes it independent of the padding).
-        first = int(np.argmax(np.asarray(jax.device_get(logits))[0, S - 1]))
+        self.cache = self._insert_jit(self.cache, cache1, jnp.int32(slot))
         now = time.perf_counter()
         req.first_token = now
         req.tokens.append(first)
@@ -138,12 +155,10 @@ class MiniEngine:
         toks = jnp.asarray(self.slot_tok.reshape(-1, 1))
         pos = jnp.asarray(self.slot_pos)
         t0 = time.perf_counter()
-        logits, self.cache = self._decode_jit(self.params, self.cache,
-                                              toks, pos)
-        logits.block_until_ready()
+        nxt, self.cache = self._decode_jit(self.params, self.cache, toks, pos)
+        nxt = np.asarray(nxt)
         dt = time.perf_counter() - t0
         self.step_log.append({"kind": "decode", "batch": len(active), "dur": dt})
-        nxt = np.asarray(jax.device_get(jnp.argmax(logits[:, 0], -1)))
         now = time.perf_counter()
         for i in active:
             req = self.slots[i]
