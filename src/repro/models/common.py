@@ -114,6 +114,9 @@ class AxisRules:
         #   attn_impl: "naive" | "blockwise";  attn_block: int
         #   rwkv_impl: "scan" | "chunked";     rwkv_chunk: int
         self.options: Dict[str, Any] = dict(options or {})
+        # what layers report while they are traced, once per compiled
+        # program (the MoE layer: its expert GEMMs' rows per token count)
+        self.notes: Dict[Any, Any] = {}
 
     def opt(self, key: str, default: Any = None) -> Any:
         return self.options.get(key, default)

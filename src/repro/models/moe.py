@@ -224,6 +224,7 @@ def moe_apply(cfg: ModelConfig, p, x: jax.Array, ax: AxisRules, *,
 
     if mesh is None or mesh.empty or tp <= 1:
         x_flat = x.reshape(B * S, D)
+        n_b = 1
         C_e = _capacity(B * S, k, E, cf, train=train)
         y, kept = _dispatch_compute_combine(
             cfg, x_flat, ids.reshape(B * S, k), gates.reshape(B * S, k),
@@ -288,6 +289,9 @@ def moe_apply(cfg: ModelConfig, p, x: jax.Array, ax: AxisRules, *,
                 check_vma=False,
             )(x, ids, gates, p["w_in"], w_gate, p["w_out"])
         total = jnp.float32(B * S * k)
+    # rows the expert GEMMs compute for B * S tokens (each batch shard
+    # fills E capacity buffers of C_e rows), and the assignments per token
+    ax.notes[("moe_expert_rows", B * S)] = (n_b * E * C_e, k)
 
     aux = {
         "moe_lb_loss": lb_loss,

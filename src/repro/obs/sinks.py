@@ -8,11 +8,7 @@ tracing``: instances (or clusters, for single-instance runs) map to
 counter tracks, and all timestamps are non-negative microseconds sorted
 monotonically.
 
-:func:`engine_events_to_chrome` is the repaired conversion for raw
-engine-event rings (the old ``EventTrace.to_chrome_trace`` emitted
-negative ``ts`` whenever an event's duration started before t=0 and
-only honoured ``dur`` on BATCH_DONE); ``core/trace.py`` now delegates
-here.
+:func:`engine_events_to_chrome` converts raw engine-event rings.
 """
 from __future__ import annotations
 
@@ -88,6 +84,8 @@ def chrome_trace_events(tel: Telemetry) -> List[dict]:
         pid, tid = pid_of(pid_label), tid_of(pid_label, tid_label)
         ts = max(s.start, 0.0) * 1e6
         args = {"rid": s.rid, **s.meta}
+        if s.parent is not None:
+            args["parent"] = s.parent
         if s.end > s.start:
             dur = (min(s.dur, s.end) if s.start < 0.0 else s.dur) * 1e6
             body.append({"name": s.kind, "ph": "X", "pid": pid, "tid": tid,
@@ -208,11 +206,11 @@ SINKS = {"chrome": write_chrome_trace, "jsonl": write_spans_jsonl,
 # ---- repaired raw engine-event conversion ---------------------------------
 
 def engine_events_to_chrome(events: Iterable[tuple]) -> List[dict]:
-    """Convert an ``EventTrace`` ring — (t, kind, data) tuples — to
-    trace events.  Any event whose data carries a numeric ``dur`` (not
-    just BATCH_DONE) becomes a duration event; starts are clamped to
-    t >= 0 with the duration truncated to match, so ``ts`` is never
-    negative."""
+    """Convert a ring of simulator engine events — (t, kind, data)
+    tuples — to trace events.  Any event whose data carries a numeric
+    ``dur`` (not just BATCH_DONE) becomes a duration event; starts are
+    clamped to t >= 0 with the duration truncated to match, so ``ts`` is
+    never negative."""
     out: List[dict] = []
     for t, kind, data in events:
         dur = data.get("dur") if isinstance(data, dict) else None

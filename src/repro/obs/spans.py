@@ -11,6 +11,10 @@ time so the hot-path record stays small.
 it occupies (queue / compute / comm / preempt).  Kinds mapped to
 ``None`` are nested detail — EP sub-graph markers live *inside* a decode
 epoch, so counting them again would double-book compute time.
+
+MiniEngine (``repro.serving.engine``) records its own kinds on the host
+clock: ``admit``, ``prefill`` and ``decode`` for its calls, and the phases
+inside them (``prefill.inputs`` ...), each pointing at its ``parent``.
 """
 from __future__ import annotations
 
@@ -31,6 +35,15 @@ SPAN_CATEGORY: Dict[str, Optional[str]] = {
     "ep_dispatch": None,
     "ep_rank": None,
     "ep_combine": None,
+    # MiniEngine: a pass's admission, its calls and the phases inside them
+    "admit": "queue",
+    "prefill": "compute",
+    "prefill.inputs": None,
+    "prefill.program": None,
+    "prefill.insert": None,
+    "decode.inputs": None,
+    "decode.program": None,
+    "decode.bookkeep": None,
 }
 
 # category priority for the attribution sweep: when intervals overlap
@@ -42,13 +55,16 @@ CATEGORY_PRIORITY = ("compute", "comm", "preempt", "queue")
 @dataclass
 class Span:
     """One typed interval.  ``rid < 0`` marks request-agnostic spans
-    (EP sub-graph markers belong to a batch, not a single request)."""
+    (EP sub-graph markers belong to a batch, not a single request).
+    ``parent`` is the index, in the recorder's ``spans``, of the span that
+    caused this one (None: a root)."""
     kind: str
     rid: int
     start: float
     end: float
     replica: str = ""
     meta: dict = field(default_factory=dict)
+    parent: Optional[int] = None
 
     @property
     def dur(self) -> float:
@@ -63,10 +79,12 @@ class Span:
              "end": self.end, "replica": self.replica}
         if self.meta:
             d["meta"] = self.meta
+        if self.parent is not None:
+            d["parent"] = self.parent
         return d
 
     @classmethod
     def from_dict(cls, d: dict) -> "Span":
         return cls(kind=d["kind"], rid=d["rid"], start=d["start"],
                    end=d["end"], replica=d.get("replica", ""),
-                   meta=d.get("meta") or {})
+                   meta=d.get("meta") or {}, parent=d.get("parent"))

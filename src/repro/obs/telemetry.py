@@ -95,16 +95,30 @@ class Telemetry:
     # ---- spans -------------------------------------------------------------
 
     def span(self, kind: str, rid: int, start: float, end: float, *,
-             replica: str = "", **meta) -> None:
+             replica: str = "", parent: Optional[int] = None,
+             **meta) -> Optional[int]:
+        """Record a span; returns its id (its index in ``spans``), or None
+        where spans are off or the cap dropped it."""
         if not self.spans_enabled:
-            return
+            return None
         if len(self.spans) >= self.max_spans:
             self.dropped_spans += 1
-            return
-        s = Span(kind, rid, start, end, replica, meta)
+            return None
+        s = Span(kind, rid, start, end, replica, meta, parent)
         self.spans.append(s)
         if rid >= 0:
             self._by_rid.setdefault(rid, []).append(s)
+        return len(self.spans) - 1
+
+    def close_span(self, sid: Optional[int], end: float, **meta) -> None:
+        """End span ``sid`` (recorded while open, with its start as its
+        end, so that the spans it causes can name it; None is ignored) and
+        add ``meta`` to it."""
+        if sid is None:
+            return
+        s = self.spans[sid]
+        s.end = end
+        s.meta.update(meta)
 
     def compute_span(self, kind: str, rid: int, start: float, end: float,
                      replica: str, **meta) -> None:

@@ -170,6 +170,35 @@ def test_span_cap_counts_drops():
     assert tel.summary_fields()["obs_dropped_spans"] == 7
 
 
+def test_spans_get_ids_children_and_close_later_under_the_cap():
+    tel = Telemetry(max_spans=3)
+    call = tel.span("decode", -1, 1.0, 1.0, batch=4)     # open: end later
+    child = tel.span("decode.program", -1, 1.1, 1.8, parent=call)
+    tel.close_span(call, 2.0, compiled=1)
+    assert (call, child) == (0, 1)
+    assert tel.spans[call].end == 2.0
+    assert tel.spans[call].meta == {"batch": 4, "compiled": 1}
+    assert tel.spans[child].parent == call
+    tel.span("admit", -1, 2.0, 2.1)
+    dropped = tel.span("decode", -1, 3.0, 3.0)
+    assert dropped is None and tel.dropped_spans == 1
+    tel.close_span(dropped, 4.0)            # a dropped span closes as a no-op
+    assert len(tel.spans) == 3
+
+
+def test_span_parent_round_trips_and_roots_write_none():
+    child = Span("prefill.program", 7, 0.5, 0.75, meta={"x": 1}, parent=3)
+    d = child.to_dict()
+    assert d["parent"] == 3
+    assert Span.from_dict(d) == child
+    root = Span("prefill_chunk", 7, 0.0, 1.0, replica="p0")
+    assert "parent" not in root.to_dict()   # the simulator's spans as before
+    assert Span.from_dict(root.to_dict()).parent is None
+    for kind in ("prefill.inputs", "prefill.program", "prefill.insert",
+                 "decode.inputs", "decode.program", "decode.bookkeep"):
+        assert SPAN_CATEGORY[kind] is None
+
+
 def test_counterboard_bounded_and_peak_preserving():
     cb = CounterBoard(max_points=32)
     for i in range(100_000):
@@ -235,6 +264,7 @@ def test_spans_jsonl_roundtrip(tmp_path):
         assert (rt.kind, rt.rid, rt.replica) == \
             (orig.kind, orig.rid, orig.replica)
         assert rt.start == orig.start and rt.end == orig.end
+        assert rt.parent is None
     assert len(back["requests"]) == len(tel.records)
     for req in back["requests"]:
         assert set(req["attribution"]) == {
@@ -265,23 +295,6 @@ def test_engine_events_to_chrome_clamps_negative_ts():
     assert batch["ts"] == 0.0 and batch["dur"] == pytest.approx(0.5e6)
     kv = next(e for e in out if e["name"] == "kv_transfer_done")
     assert kv["ph"] == "X" and kv["dur"] == pytest.approx(0.25e6)
-
-
-def test_event_trace_to_chrome_shim(tmp_path):
-    from repro.core.events import EV
-    from repro.core.trace import EventTrace
-    tr = EventTrace(capacity=16)
-
-    class _Ev:
-        def __init__(self, time, kind, data):
-            self.time, self.kind, self.data = time, kind, data
-
-    tr(_Ev(0.2, EV.BATCH_DONE, {"dur": 1.0}))
-    tr(_Ev(0.4, EV.TOKEN_GENERATED, {}))
-    out = tmp_path / "shim.json"
-    tr.to_chrome_trace(str(out))
-    data = json.loads(out.read_text())
-    assert all(e["ts"] >= 0 for e in data["traceEvents"])
 
 
 # ----------------------------------------------------------------- fleet --
