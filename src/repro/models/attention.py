@@ -212,64 +212,60 @@ def attention_decode(cfg: ModelConfig, p, x, cache: Dict[str, jax.Array],
                      pos: jax.Array, ax: AxisRules, *, window: int = 0,
                      memory_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode.  x (B,1,D); cache k/v (B,Sc,K,hd); pos scalar int.
+    """One-token decode.  x (B,1,D); cache k/v (B,Sc,K,hd); pos (B,) int.
 
-    Sliding-window caches are ring buffers of length ``min(window, S)``;
-    entries carry RoPE at their absolute positions so no re-rotation is
-    needed.  Cross-attention (enc-dec) passes precomputed ``memory_kv``.
+    Reads the cache as it stands and returns the output with the new
+    token's K/V rows ``{"k", "v"}`` (B,K,hd), which the caller writes at
+    slot ``pos % Sc`` (``transformer.write_decode``): the softmax runs over
+    the cache's valid entries and the new row's own score together, so the
+    cache is never rewritten here.  Sliding-window caches are ring buffers
+    of length ``min(window, S)``; entries carry RoPE at their absolute
+    positions so no re-rotation is needed.  Cross-attention (enc-dec)
+    passes precomputed ``memory_kv`` and returns no rows.
     """
     B = x.shape[0]
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     scale = hd ** -0.5
 
-    def attend(q, ck, cv, bias):
-        # q (B,H,hd); ck/cv (B,T,K,hd); bias (T,) or per-row (B,T), f32
+    def scores(q, k):
+        # q (B,H,hd); k (B,T,K,hd) -> (B,K,G,T), f32
         qg = q.reshape(B, K, H // K, hd)
-        s = jnp.einsum("bkgd,btkd->bkgt", qg, ck).astype(jnp.float32) * scale
+        s = jnp.einsum("bkgd,btkd->bkgt", qg, k).astype(jnp.float32) * scale
         if cfg.attn_logit_softcap:
             s = softcap(s, cfg.attn_logit_softcap)
-        s = s + (bias[:, None, None, :] if bias.ndim == 2
-                 else bias[None, None, None, :])
-        pr = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("bkgt,btkd->bkgd", pr, cv)
-        return o.reshape(B, H, hd)
+        return s
 
-    if memory_kv is not None:  # cross-attention: cache is static memory KV
+    def mix(pr, v):
+        # pr (B,K,G,T) f32; v (B,T,K,hd) -> (B,K,G,hd), f32
+        return jnp.einsum("bkgt,btkd->bkgd", pr.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    def out(o):
+        y = jnp.einsum("bhk,hkd->bd", o.reshape(B, H, hd).astype(x.dtype),
+                       p["wo"])[:, None, :]
+        return ax.constrain(y, "batch", None, "embed")
+
+    if memory_kv is not None:  # cross-attention: static memory KV
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])[:, 0]
         ck, cv = memory_kv
-        o = attend(q, ck, cv, jnp.zeros((ck.shape[1],), jnp.float32))
-        y = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-        return ax.constrain(y, "batch", None, "embed"), cache
+        return out(mix(jax.nn.softmax(scores(q, ck), axis=-1), cv)), {}
 
-    # pos: scalar (uniform batch, dry-run decode) or (B,) per-row positions
-    # (continuous batching in the real serving engine).
-    per_row = getattr(pos, "ndim", 0) == 1
-    pos_b = (pos[:, None] if per_row
-             else jnp.broadcast_to(pos[None, None], (B, 1)))
-    q, k_new, v_new = _project_qkv(cfg, p, x, pos_b, ax)
-    q = q[:, 0]  # (B,H,hd)
-
-    ck, cv = cache["k"], cache["v"]
+    ck = ax.constrain(cache["k"], "batch", "kv_seq", None, None)
+    cv = ax.constrain(cache["v"], "batch", "kv_seq", None, None)
+    q, k_new, v_new = _project_qkv(cfg, p, x, pos[:, None], ax)
+    # the new row as the cache will hold it
+    k_new, v_new = k_new.astype(ck.dtype), v_new.astype(cv.dtype)
     Sc = ck.shape[1]
-    t = jnp.arange(Sc)
-    if per_row:
-        slot = pos % Sc                                   # (B,)
-        hit = (t[None, :] == slot[:, None])[..., None, None]
-        ck = jnp.where(hit, k_new, ck)
-        cv = jnp.where(hit, v_new, cv)
-        valid = (t[None, :] <= pos[:, None]) | (pos[:, None] + 1 >= Sc)
-        bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)  # (B,Sc)
-    else:
-        slot = pos % Sc  # ring semantics; Sc == full length when window == 0
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k_new, slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v_new, slot, axis=1)
-        # validity: ring buffer is fully valid once pos+1 >= Sc; otherwise
-        # only the first pos+1 slots hold real entries.
-        valid = (t <= pos) | (pos + 1 >= Sc)
-        bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
-    ck = ax.constrain(ck, "batch", "kv_seq", None, None)
-    cv = ax.constrain(cv, "batch", "kv_seq", None, None)
-
-    o = attend(q, ck, cv, bias)
-    y = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-    return ax.constrain(y, "batch", None, "embed"), {"k": ck, "v": cv}
+    t = jnp.arange(Sc)[None, :]
+    pos = pos[:, None]
+    # old entries that stay attendable: a ring buffer is full once
+    # pos + 1 >= Sc, else only t < pos holds real entries; slot pos % Sc is
+    # the one the new row replaces (position pos - Sc, out of the window)
+    valid = ((t <= pos) | (pos + 1 >= Sc)) & (t != pos % Sc)
+    s = jnp.where(valid[:, None, None, :], scores(q[:, 0], ck), NEG_INF)
+    s1 = scores(q[:, 0], k_new)                         # (B,K,G,1)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s1)
+    e, e1 = jnp.exp(s - m), jnp.exp(s1 - m)
+    l = jnp.sum(e, axis=-1, keepdims=True) + e1
+    o = mix(e / l, cv) + mix(e1 / l, v_new)
+    return out(o), {"k": k_new[:, 0], "v": v_new[:, 0]}

@@ -176,23 +176,30 @@ class LM:
         return x, {"groups": gcaches, "tail": tuple(tcaches)}
 
     def _scan_decode(self, params, cache, x, pos):
+        """One token through the stack.  The scan reads each layer's cache
+        and hands back what changed (new K/V rows, recurrent states); they
+        are written after it (``write_decode``), so the stacked cache is
+        never carried or copied.  A scalar ``pos`` is every row's."""
         cfg, ax = self.cfg, self.ax
         kinds = self.period_kinds
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (x.shape[0],))
 
         def group_fn(x, scanned):
             gp, gc = scanned
-            newc = []
+            new = []
             for s, kind in enumerate(kinds):
-                x, c = tfm.block_decode(cfg, kind, gp[s], x, gc[s], pos, ax)
-                newc.append(c)
-            return x, tuple(newc)
+                x, n = tfm.block_decode(cfg, kind, gp[s], x, gc[s], pos, ax)
+                new.append(n)
+            return x, tuple(new)
 
-        x, gcaches = jax.lax.scan(group_fn, x, (params["groups"], cache["groups"]))
-        tcaches = []
+        x, gnew = jax.lax.scan(group_fn, x, (params["groups"], cache["groups"]))
+        groups = tuple(tfm.write_decode(kind, c, n, pos)
+                       for kind, c, n in zip(kinds, cache["groups"], gnew))
+        tail = []
         for kind, tp_, tc in zip(self.tail_kinds, params["tail"], cache["tail"]):
-            x, c = tfm.block_decode(cfg, kind, tp_, x, tc, pos, ax)
-            tcaches.append(c)
-        return x, {"groups": gcaches, "tail": tuple(tcaches)}
+            x, n = tfm.block_decode(cfg, kind, tp_, x, tc, pos, ax)
+            tail.append(tfm.write_decode(kind, tc, n, pos))
+        return x, {"groups": groups, "tail": tuple(tail)}
 
     # -------------------------------------------------------------- steps --
     def loss(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:

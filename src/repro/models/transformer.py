@@ -227,16 +227,16 @@ def _kv_to_cache(cfg, k, v, cache_len, window, ax: AxisRules):
 
 def block_decode(cfg: ModelConfig, kind: str, p, x, cache, pos, ax: AxisRules,
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Single-token step.  x (B,1,D); pos scalar int32."""
+    """Single-token step.  x (B,1,D); pos (B,) int32.  Returns the output
+    and what changed of the block's cache, for ``write_decode``: the new
+    K/V rows of an attention block, the whole new state of a recurrent one.
+    """
     if kind in (ATTN_GLOBAL, ATTN_LOCAL):
         window = cfg.sliding_window if kind == ATTN_LOCAL else 0
         h = rms_norm(x, p["ln1"], cfg.rms_eps, zero_centered=True)
-        kv_cache = {"k": cache["k"], "v": cache["v"]}
-        a, kv_cache = attn.attention_decode(cfg, p["attn"], h, kv_cache, pos, ax,
-                                            window=window)
+        a, rows = attn.attention_decode(cfg, p["attn"], h, cache, pos, ax,
+                                        window=window)
         x = x + _post(cfg, p, "ln1_post", a)
-        new_cache = dict(cache)
-        new_cache.update(kv_cache)
         if cfg.cross_attention and "xk" in cache:
             hx = rms_norm(x, p["ln_x"], cfg.rms_eps, zero_centered=True)
             a, _ = attn.attention_decode(cfg, p["xattn"], hx, {}, pos, ax,
@@ -244,7 +244,7 @@ def block_decode(cfg: ModelConfig, kind: str, p, x, cache, pos, ax: AxisRules,
             x = x + a
         h = rms_norm(x, p["ln2"], cfg.rms_eps, zero_centered=True)
         f, _ = _ffn_train(cfg, p, h, ax, train=False)
-        return x + _post(cfg, p, "ln2_post", f), new_cache
+        return x + _post(cfg, p, "ln2_post", f), rows
     if kind == RWKV:
         h = rms_norm(x, p["ln1"], cfg.rms_eps, zero_centered=True)
         y, tm_shift, state = rwkv_mod.timemix_decode(
@@ -265,3 +265,28 @@ def block_decode(cfg: ModelConfig, kind: str, p, x, cache, pos, ax: AxisRules,
         x = x + mlp_mod.mlp_apply(cfg, p["mlp"], h, ax)
         return x, {"conv_tail": tail, "h": hlast}
     raise ValueError(kind)
+
+
+def write_decode(kind: str, cache, new, pos) -> Dict[str, jax.Array]:
+    """A block's cache after a decode step, from what ``block_decode``
+    returned.  Attention K/V rows (B,K,hd) are written at slot ``pos % Sc``
+    of each batch row and nothing else of the cache moves, so a donated
+    cache is updated in place; leaves with no update (cross-attention
+    memory) are kept; recurrent states are small and replaced whole.
+    Leaves may carry a leading layer axis (the scanned groups), as may
+    ``new``."""
+    if kind not in (ATTN_GLOBAL, ATTN_LOCAL):
+        return {**cache, **new}
+    out = dict(cache)
+    for name in ("k", "v"):
+        c = cache[name]
+        # every index, the layer's too, is a scatter index, so each update
+        # is one (K, hd) row, contiguous in the cache's layout; a slice over
+        # the layer axis has XLA relayout the whole cache around the scatter
+        idx = (jnp.arange(c.shape[-4]), pos % c.shape[-3])
+        if c.ndim == 5:
+            idx = (jnp.arange(c.shape[0])[:, None],) + tuple(
+                i[None] for i in idx)
+        out[name] = c.at[idx].set(new[name].astype(c.dtype),
+                                  unique_indices=True)
+    return out

@@ -6,6 +6,8 @@ covering every block family we additionally assert PREFILL+DECODE ==
 TEACHER-FORCED FORWARD — the strongest correctness property of the cache
 path (ring buffers, RoPE positions, recurrent states, cross-attention).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,8 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config
 from repro.models import NO_RULES, build_model, init_tree
+from repro.models.common import shape_tree
+from repro.serving.engine import _insert_slot
 
 B, S = 2, 16
 
@@ -110,6 +114,55 @@ def test_per_row_positions_match_uniform():
     l2, _ = model.decode(params, cache, nxt, jnp.full((B,), 8, jnp.int32))
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("arch, prefix", [
+    ("yi-9b", (5, 11, 17)),         # global attention
+    ("mixtral-8x7b", (9, 21, 28)),  # window 16: two rows' rings have wrapped
+])
+def test_staggered_rows_decode_in_place(arch, prefix):
+    """Rows at different positions in one batch, as MiniEngine serves them:
+    each row's prefix is prefilled alone and put in its slot with
+    ``_insert_slot``.  Each decode step matches every row's full-sequence
+    logits at its own position, and changes nothing of the cache but one
+    K/V row per slot and layer, at ``pos % Sc``."""
+    cfg = get_config(arch, smoke=True)
+    if cfg.moe is not None:  # dropless, so prefill and decode route alike
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor_eval=float(cfg.moe.num_experts)))
+    model = build_model(cfg, NO_RULES)
+    params = init_tree(jax.random.PRNGKey(3), model.pds(), jnp.float32)
+    rng = np.random.default_rng(3)
+    rows, steps, max_seq = len(prefix), 4, 40
+    toks = rng.integers(0, cfg.vocab_size, (rows, max(prefix) + steps))
+    cache = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        shape_tree(model.cache_pds(rows, max_seq), jnp.float32))
+    want = []
+    for b, n in enumerate(prefix):
+        seq = jnp.asarray(toks[b:b + 1, :n + steps], jnp.int32)
+        full, _ = model.prefill(params, {"tokens": seq}, all_logits=True)
+        want.append(np.asarray(full[0, n:]))
+        _, one = model.prefill(params, {"tokens": seq[:, :n]},
+                               cache_len=max_seq)
+        cache = _insert_slot(cache, one, b)
+
+    decode = jax.jit(model.decode)
+    pos = np.asarray(prefix, np.int32)
+    for i in range(steps):
+        cur = jnp.asarray(toks[np.arange(rows), pos][:, None], jnp.int32)
+        logits, new = decode(params, cache, cur, jnp.asarray(pos))
+        for b in range(rows):
+            np.testing.assert_allclose(np.asarray(logits[b, 0]), want[b][i],
+                                       atol=2e-3, rtol=2e-3)
+        for old_kv, new_kv in zip(jax.tree_util.tree_leaves(cache),
+                                  jax.tree_util.tree_leaves(new)):
+            old_kv, new_kv = np.asarray(old_kv), np.asarray(new_kv)
+            hit = np.zeros(old_kv.shape[:3], bool)  # (layers, rows, Sc)
+            hit[:, np.arange(rows), pos % old_kv.shape[2]] = True
+            np.testing.assert_array_equal(new_kv[~hit], old_kv[~hit])
+            assert (new_kv[hit] != old_kv[hit]).any(axis=(-2, -1)).all()
+        cache, pos = new, pos + 1
 
 
 def test_param_counts_match_full_configs():
